@@ -18,7 +18,6 @@ import jax.numpy as jnp                       # noqa: E402
 from jax.sharding import PartitionSpec as P   # noqa: E402
 
 from repro.comm.costs import estimate_sync_time     # noqa: E402
-from repro.compat import shard_map                  # noqa: E402
 from repro.core.channels import plan_for            # noqa: E402
 from repro.core.endpoints import Category           # noqa: E402
 from repro.launch.mesh import make_mesh             # noqa: E402
@@ -50,7 +49,7 @@ def main():
         out, _ = jax.lax.scan(body, grid, None, length=STEPS)
         return out
 
-    sharded = shard_map(run, mesh=mesh, in_specs=P("ranks"),
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=P("ranks"),
                             out_specs=P("ranks"))
     grid = jax.random.normal(jax.random.PRNGKey(0), (GRID, GRID))
     out = jax.jit(sharded)(grid)

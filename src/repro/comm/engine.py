@@ -25,7 +25,6 @@ from typing import Sequence, Union
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 from repro.core.channels import ChannelPlan, plan_for
 from repro.core.endpoints import Category
 from repro.comm.bucketing import (BucketPlan, make_bucket_plan, pack_buckets,
@@ -82,7 +81,7 @@ class GradSyncEngine:
     def world_size(self):
         n = 1
         for ax in self.axis_names:
-            n *= axis_size(ax)
+            n *= jax.lax.axis_size(ax)
         return n
 
     def __call__(self, grads, compressor_state=()):

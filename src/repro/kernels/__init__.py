@@ -12,6 +12,6 @@ assigned architectures need at the dry-run shapes (DESIGN.md §5):
   rglru/            RG-LRU gated linear recurrence, block-parallel scan
 
 Each ships as kernel.py (pl.pallas_call + BlockSpec; TPU is the TARGET),
-ops.py (jit'd wrapper; interpret=True on CPU), ref.py (pure-jnp oracle for
-the allclose sweeps).
+ops.py (jit'd wrapper; interpreted on the CPU, see mode.py), ref.py
+(pure-jnp oracle for the allclose sweeps).
 """

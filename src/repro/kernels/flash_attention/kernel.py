@@ -22,10 +22,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   causal: bool, window: int, softcap: float, scale: float,
@@ -171,7 +167,7 @@ def ragged_decode_bhsd(q, k, v, cur_index, *, softcap: float = 0.0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bhkv, g, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cur_index.astype(jnp.int32), q, k, v)
@@ -270,7 +266,7 @@ def paged_decode_bhsd(q, k, v, page_table, cur_index, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bhkv, g, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cur_index.astype(jnp.int32), page_table.astype(jnp.int32), q, k, v)
@@ -314,7 +310,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((q_block,), jnp.float32),
             pltpu.VMEM((q_block,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

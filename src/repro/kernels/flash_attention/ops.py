@@ -1,4 +1,4 @@
-"""Public jit'd wrapper: (B, S, H, dh) layout, auto interpret on CPU."""
+"""Public jit'd wrappers: (B, S, H, dh) layout, interpreted on the CPU."""
 
 from __future__ import annotations
 
@@ -10,10 +10,7 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention.kernel import (flash_attention_bhsd,
                                                   paged_decode_bhsd,
                                                   ragged_decode_bhsd)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.mode import interpret_mode
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap", "q_block",
@@ -23,11 +20,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     kv_block: int = 1024, interpret: bool = None):
     """q: (B, Sq, Hq, dh); k/v: (B, Sk, Hkv, dh) -> (B, Sq, Hq, dh).
 
-    Lowers the Pallas TPU kernel on TPU; everywhere else runs the kernel
-    body under the Pallas interpreter (bit-exact semantics, CPU-testable).
+    Lowers the Pallas TPU kernel on TPU and runs the kernel body under
+    the Pallas interpreter on the CPU (``kernels.mode.interpret_mode``).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     b, sq, hq, dh = q.shape
     hkv = k.shape[2]
     # (B, S, H, dh) -> heads-major (B*H, S, dh)
@@ -51,7 +48,7 @@ def flash_decode_attention(q, k_cache, v_cache, cur_index, *,
     (``models.attention.attention_decode`` with a vector index is the
     oracle).  -> (B, 1, Hq, dh)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     b, _, hq, dh = q.shape
     hkv = k_cache.shape[2]
     g = hq // hkv
@@ -80,7 +77,7 @@ def paged_flash_decode_attention(q, k_pages, v_pages, page_table,
     gather oracle ``models.attention.attention_decode_paged``.
     -> (B, 1, Hq, dh)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     b, _, hq, dh = q.shape
     n, ps, hkv = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     g = hq // hkv

@@ -4,7 +4,7 @@ kernel.
 TPU-native blocking (DESIGN.md §5): channels are embarrassingly parallel
 (VPU lanes), time is sequential — so the grid is
 (batch, channel_blocks, time_blocks) with the time dim "arbitrary" and the
-(channel_block,) fp32 carry held in VMEM scratch across time blocks.  Each
+(1, channel_block) fp32 carry held in VMEM scratch across time blocks.  Each
 program instance streams one (time_block x channel_block) tile HBM->VMEM
 and walks its rows; channel_block should be a multiple of 128 lanes on
 real hardware.
@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _rglru_kernel(a_ref, x_ref, o_ref, h_ref, *, t_block: int):
     tj = pl.program_id(2)
@@ -31,11 +27,13 @@ def _rglru_kernel(a_ref, x_ref, o_ref, h_ref, *, t_block: int):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
+    # rows are read as (1, c_block) tiles: Mosaic lays out 2-D vectors
+    # only, and a 1-D row crashes its layout inference
     def step(i, h):
-        a_i = a_ref[0, i, :].astype(jnp.float32)
-        x_i = x_ref[0, i, :].astype(jnp.float32)
+        a_i = a_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        x_i = x_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
         h = a_i * h + x_i
-        o_ref[0, i, :] = h.astype(o_ref.dtype)
+        o_ref[0, pl.ds(i, 1), :] = h.astype(o_ref.dtype)
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, t_block, step, h_ref[...])
@@ -64,8 +62,8 @@ def rglru_scan_btc(a, x, *, t_block: int = 256, c_block: int = 128,
         out_specs=pl.BlockSpec((1, t_block, c_block),
                                lambda bi, ci, tj: (bi, tj, ci)),
         out_shape=jax.ShapeDtypeStruct((b, t, c), x.dtype),
-        scratch_shapes=[pltpu.VMEM((c_block,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, c_block), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, x)

@@ -6,6 +6,7 @@ from functools import partial
 
 import jax
 
+from repro.kernels.mode import interpret_mode
 from repro.kernels.rglru.kernel import rglru_scan_btc
 
 
@@ -14,8 +15,8 @@ def rglru_scan(a, x, *, t_block: int = 256, c_block: int = 128,
                interpret: bool = None):
     """a, x: (B, T, C) -> h with h_t = a_t h_{t-1} + x_t.
 
-    Pallas TPU kernel on TPU; interpreter elsewhere (CPU tests)."""
+    Pallas TPU kernel on TPU; interpreter on the CPU (tests)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     return rglru_scan_btc(a, x, t_block=t_block, c_block=c_block,
                           interpret=interpret)

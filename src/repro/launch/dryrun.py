@@ -23,7 +23,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis, set_mesh
 from repro.configs import ARCHS, get_config
 from repro.launch.hlo_analysis import analyze
 from repro.launch.mesh import make_production_mesh
@@ -117,7 +116,7 @@ def lower_cell(arch: str, shape_name: str, mesh, rules_preset: str = "auto",
             params_sds)
 
     accum = accum_override or auto_accum(cfg, cell, mesh, rules)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if cell.kind == "train":
             opt, opt_sds = _opt_specs(model, mesh, rules, params_sds,
                                       preset, master_fp32=params_bf16)
@@ -147,7 +146,7 @@ def lower_cell(arch: str, shape_name: str, mesh, rules_preset: str = "auto",
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = analyze(compiled.as_text())
     n_chips = mesh.devices.size
     record = {

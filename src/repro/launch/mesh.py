@@ -15,8 +15,6 @@ from typing import Sequence
 
 import jax
 
-from repro.compat import mesh_axis_types_kwargs
-
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices=None) -> jax.sharding.Mesh:
@@ -27,8 +25,9 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str],
             f"mesh {tuple(shape)} needs {n} devices, have {len(devices)} "
             "(the dry-run must set XLA_FLAGS="
             "--xla_force_host_platform_device_count before importing jax)")
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
     return jax.make_mesh(tuple(shape), tuple(axes), devices=devices[:n],
-                         **mesh_axis_types_kwargs(len(axes)))
+                         axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -31,6 +31,7 @@ import numpy as np
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.core.endpoints import Category
 from repro.core.plan import EndpointPlan, Hints, SharingVector
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import enabled_obs
 from repro.serve import connect
 from repro.serve.fabric import TRAFFIC_SHAPES, bursty_trace, phased_trace, \
@@ -331,6 +332,8 @@ def run_single(cfg, client, args) -> None:
 
 
 def main(argv=None):
+    """Serve the requests the flags describe; -> the connected client,
+    whose ``results`` hold every completed request's tokens."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b", choices=list(ARCHS))
     ap.add_argument("--smoke", action="store_true")
@@ -487,6 +490,7 @@ def main(argv=None):
             kw["shed_capacity"] = args.shed_capacity
         recovery = RecoveryPolicy(**kw)
     plan = build_plan(args, ap)
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     obs = enabled_obs() if (args.trace_out or args.metrics_out) else None
     client = connect(cfg, plan, seed=args.seed, obs=obs,
@@ -504,6 +508,7 @@ def main(argv=None):
         obs.metrics.dump(args.metrics_out)
         print(f"metrics: {len(obs.metrics.names())} series -> "
               f"{args.metrics_out}")
+    return client
 
 
 if __name__ == "__main__":

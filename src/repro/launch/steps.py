@@ -11,7 +11,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.comm.engine import GradSyncEngine
-from repro.compat import shard_map
 from repro.core.endpoints import Category
 from repro.launch.mesh import data_axes
 from repro.models.model import Model
@@ -133,7 +132,7 @@ def make_ddp_train_step(model: Model, opt: AdamW, mesh,
 
     batch_rank_specs = P(axes if len(axes) > 1 else axes[0])
     shard = partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), batch_rank_specs, P()),
         out_specs=(P(), P(), P(), P()))
     return shard(step), engine

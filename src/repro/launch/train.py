@@ -19,6 +19,7 @@ import jax
 
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.core.endpoints import Category
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.train.loop import TrainConfig, Trainer
 
@@ -40,6 +41,7 @@ def main(argv=None):
     ap.add_argument("--metrics", default="metrics.jsonl")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = None
     if args.mode == "ddp":

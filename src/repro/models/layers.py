@@ -58,12 +58,7 @@ def _match_vma(cot, primal_like, data_like):
     must itself be unvarying: psum over the axes the data varies on —
     which is exactly the correct gradient reduction for replicated
     parameters."""
-    try:
-        cot_vma = jax.typeof(cot).vma
-        prim_vma = jax.typeof(primal_like).vma
-    except (AttributeError, TypeError):
-        return cot
-    extra = tuple(sorted(cot_vma - prim_vma))
+    extra = tuple(sorted(jax.typeof(cot).vma - jax.typeof(primal_like).vma))
     if extra:
         cot = jax.lax.psum(cot, extra)
     return cot

@@ -208,16 +208,21 @@ def _attn_cache_write_paged(cache, k_new, v_new, idx, page_table,
     return {"k": k, "v": v}
 
 
-def _ragged_kv_block(smax: int, target: int = 256) -> int:
-    """Largest divisor of the cache length <= ``target`` — the kernel
-    requires kv_block | Smax, and Smax (= engine max_len) is static.
-    Callers must fall back to the jnp path when this degrades (a
-    near-prime Smax has only tiny divisors, and a 1-wide kv block means
-    Smax sequential grid steps per layer)."""
-    for kb in range(min(target, smax), 0, -1):
-        if smax % kb == 0:
-            return kb
-    return smax
+def ragged_kv_block(smax: int, target: int = 256) -> int:
+    """Largest divisor of the cache length <= ``target`` — the ragged
+    decode kernel requires kv_block | Smax, and Smax (= engine max_len)
+    is static.  Raises when that divisor degrades below
+    ``min(64, Smax)``: a near-prime Smax has only tiny divisors, and a
+    1-wide kv block means Smax sequential grid steps per layer."""
+    kb = next(d for d in range(min(target, smax), 0, -1) if smax % d == 0)
+    if kb < min(64, smax):
+        raise ValueError(
+            f"the ragged decode kernel cannot take max_len={smax}: its "
+            f"largest divisor <= {target} is {kb}, below the "
+            f"{min(64, smax)}-wide kv block the kernel needs; pick a "
+            f"max_len with a divisor in [64, {target}] (a multiple of "
+            f"64) or serve without use_ragged_kernel")
+    return kb
 
 
 def _decode_valid_mask(smax, idx, window: int, rolling: bool):
@@ -285,18 +290,16 @@ def _self_attention(p, h, ctx: BlockCtx, window: int, cache):
                                    ctx.decode_idx, valid_mask=valid,
                                    softcap=cfg.attn_logit_softcap)
         elif (ctx.ragged_kernel and window == 0
-                and jnp.ndim(ctx.decode_idx) == 1
-                and _ragged_kv_block(cache["k"].shape[1])
-                >= min(64, cache["k"].shape[1])):
+                and jnp.ndim(ctx.decode_idx) == 1):
             # per-slot full-context decode: the ragged Pallas kernel skips
             # whole kv blocks past each slot's length (TPU data path;
-            # interpret mode on CPU — ops.py picks per backend)
+            # interpret mode on CPU — kernels.mode decides per backend)
             from repro.kernels.flash_attention.ops import \
                 flash_decode_attention
             out = flash_decode_attention(
                 q, new_kv["k"], new_kv["v"], ctx.decode_idx,
                 softcap=cfg.attn_logit_softcap,
-                kv_block=_ragged_kv_block(new_kv["k"].shape[1]))
+                kv_block=ragged_kv_block(new_kv["k"].shape[1]))
         else:
             out = attention_decode(q, new_kv["k"], new_kv["v"],
                                    ctx.decode_idx, window=window,

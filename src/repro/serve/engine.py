@@ -53,6 +53,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.plan import EndpointPlan, SharingVector
 from repro.models.model import Model
+from repro.models.transformer import ragged_kv_block
 from repro.serve.pages import PagePool, sentinel
 from repro.serve.slots import SlotPool, _coerce_level
 
@@ -564,6 +565,8 @@ class ContinuousEngine:
             # contiguous stats dict (and committed golden) is unchanged
             self.stats["page_deferrals"] = 0
             self.stats["page_hwm"] = 0
+        elif use_ragged_kernel:
+            ragged_kv_block(max_len)     # refuse a max_len it cannot take
         self.prefill_buckets = self._resolve_buckets(prefill_buckets)
         self._t0 = 0.0
         self._started = False
@@ -918,6 +921,21 @@ class ContinuousEngine:
             if probe is not None:
                 total += probe()
         return total
+
+    def decode_program_text(self) -> str:
+        """Compiled text of the decode executable this engine dispatches
+        (the fused horizon when ``decode_horizon > 1``, else the single
+        step), lowered for its live cache — where a kernel on the decode
+        path shows up as a custom call."""
+        self.start()
+        if self.decode_horizon > 1:
+            lowered = self._steps.horizon.lower(
+                self.params, self._cache, self._dev_state,
+                self.decode_horizon, self.max_len)
+        else:
+            lowered = self._decode.lower(self.params, self._cache,
+                                         jnp.asarray(self._next_tok))
+        return lowered.compile().as_text()
 
     def regroup(self, slot_level: Optional[int] = None,
                 exec_group: Optional[int] = None,

@@ -59,10 +59,14 @@ def test_constant_telemetry_never_oscillates(vector, pressure, windows):
     for r in RESOURCES:
         assert len(deltas[r]) <= 1, \
             f"{r} moved both directions under constant telemetry"
-    # convergence: after the trajectory's worst-case horizon, no
-    # further transitions fire on the same telemetry
+    # convergence: within the trajectory's worst-case horizon the levels
+    # stop moving, and no further transitions fire on the same telemetry
+    # (after only `windows` windows a demotion chain may still be paying
+    # its patience and cooldown)
+    horizon = rp.max_windows_to_reach(3) + 1
+    drive(rp, stats_for(pressure), horizon)
     settled = rp.vector
-    drive(rp, stats_for(pressure), rp.max_windows_to_reach(3) + 1)
+    drive(rp, stats_for(pressure), horizon)
     assert rp.vector == settled
 
 

@@ -2,6 +2,7 @@
 HLO-level collective schedule (multi-device parts run in a subprocess with
 forced host devices so the main test process keeps 1 device)."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -15,7 +16,6 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np, re
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.core.endpoints import Category
     from repro.comm.engine import GradSyncEngine
     from repro.launch.mesh import make_mesh
@@ -31,7 +31,7 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     results, n_ar, nbytes = {}, {}, {}
     for cat in Category:
         eng = GradSyncEngine(cat, axis_names=("data",))
-        f = shard_map(lambda g: eng(g)[0], mesh=mesh, in_specs=(P(),),
+        f = jax.shard_map(lambda g: eng(g)[0], mesh=mesh, in_specs=(P(),),
                           out_specs=P())
         results[cat] = jax.jit(f)(grads)
         c = analyze(jax.jit(f).lower(grads).compile().as_text())
@@ -62,6 +62,7 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
 def test_categories_equivalent_and_schedules_differ():
     res = subprocess.run([sys.executable, "-c", SUBPROCESS_SCRIPT],
                          capture_output=True, text=True, cwd=".",
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"},
                          timeout=420)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "OK" in res.stdout

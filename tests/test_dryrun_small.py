@@ -2,6 +2,7 @@
 sets XLA_FLAGS before importing jax; the production 512-device sweep runs
 via `python -m repro.launch.dryrun --all`)."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -44,6 +45,7 @@ SCRIPT = textwrap.dedent("""
 def test_dryrun_cell_and_multipod():
     res = subprocess.run([sys.executable, "-c", SCRIPT],
                          capture_output=True, text=True, cwd=".",
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"},
                          timeout=560)
     assert res.returncode == 0, res.stdout[-2000:] + res.stderr[-4000:]
     assert "OK" in res.stdout

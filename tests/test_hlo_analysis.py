@@ -1,6 +1,7 @@
 """Loop-aware HLO walker: exact trip-count handling, dot flops, collective
 parsing (multi-device case in a subprocess)."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -9,7 +10,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import cost_analysis
 from repro.launch.hlo_analysis import analyze
 
 
@@ -62,7 +62,7 @@ def test_xla_cost_analysis_loop_unaware_documented():
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     ws = jax.ShapeDtypeStruct((10, 128, 128), jnp.float32)
     compiled = jax.jit(scanned).lower(x, ws).compile()
-    xla_flops = cost_analysis(compiled)["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     assert xla_flops < 2 * 128 ** 3 * 10 / 2      # body counted ~once
 
 
@@ -73,14 +73,13 @@ SUBPROCESS_COLLECTIVES = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.launch.mesh import make_mesh
     from repro.launch.hlo_analysis import analyze
 
     mesh = make_mesh((8,), ("data",))
     def f(x):
         return jax.lax.psum(x * 2, "data")
-    sf = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
+    sf = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
     x = jax.ShapeDtypeStruct((8, 1024), jnp.float32)
     c = analyze(jax.jit(sf).lower(x).compile().as_text())
     assert c.collective_counts.get("all-reduce", 0) >= 1, c.collective_counts
@@ -92,7 +91,7 @@ SUBPROCESS_COLLECTIVES = textwrap.dedent("""
             return c_ + jax.lax.psum(xi[0], "data"), None
         out, _ = jax.lax.scan(body, jnp.zeros((1024,)), x)
         return out
-    sg = shard_map(g, mesh=mesh, in_specs=P(None, "data"),
+    sg = jax.shard_map(g, mesh=mesh, in_specs=P(None, "data"),
                        out_specs=P())
     x2 = jax.ShapeDtypeStruct((6, 8, 1024), jnp.float32)
     c2 = analyze(jax.jit(sg).lower(x2).compile().as_text())
@@ -106,6 +105,7 @@ SUBPROCESS_COLLECTIVES = textwrap.dedent("""
 def test_collectives_parsed_with_trip_counts():
     res = subprocess.run([sys.executable, "-c", SUBPROCESS_COLLECTIVES],
                          capture_output=True, text=True, cwd=".",
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"},
                          timeout=300)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "OK" in res.stdout

@@ -156,6 +156,63 @@ def test_continuous_engine_ragged_kernel_same_tokens():
     assert serve(False) == serve(True)
 
 
+def test_decode_program_text_is_the_engine_decode_step():
+    """``decode_program_text`` compiles the decode executable for the
+    engine's live cache without disturbing it: serving afterwards gives
+    the same tokens as an engine that never asked."""
+    from repro.configs import get_smoke_config
+    from repro.models.model import Model
+    from repro.serve.engine import ContinuousEngine, Request
+    cfg = get_smoke_config("qwen2-0.5b")
+    params = Model(cfg).init(jax.random.PRNGKey(0))
+
+    def serve(inspect):
+        eng = ContinuousEngine(cfg, params, n_slots=2, max_len=64,
+                               decode_horizon=4, use_ragged_kernel=True)
+        if inspect:
+            assert "ENTRY" in eng.decode_program_text()
+        eng.submit(Request(rid=0, prompt=np.arange(1, 9, dtype=np.int32),
+                           max_new_tokens=6))
+        return eng.run()[0].output
+
+    assert serve(True) == serve(False)
+
+
+def test_ragged_kernel_refuses_max_len_it_cannot_take():
+    """A prime max_len above 256 leaves the kernel no kv block it can
+    take: the model step and the engine raise, naming max_len, instead
+    of serving jnp attention in silence.  Without the kernel any
+    max_len serves."""
+    from repro.configs import get_smoke_config
+    from repro.models.model import Model
+    from repro.models.transformer import ragged_kv_block
+    from repro.serve.engine import ContinuousEngine
+    assert ragged_kv_block(512) == 256 and ragged_kv_block(48) == 48
+    cfg = get_smoke_config("qwen2-0.5b")
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    cache = m.init_cache(2, 257, per_slot=True)
+    tok = jnp.asarray([1, 2], jnp.int32)
+    with pytest.raises(ValueError, match="max_len=257"):
+        m.decode_step(params, cache, tokens=tok, use_ragged_kernel=True)
+    with pytest.raises(ValueError, match="max_len=257"):
+        ContinuousEngine(cfg, params, n_slots=2, max_len=257,
+                         use_ragged_kernel=True)
+    ContinuousEngine(cfg, params, n_slots=2, max_len=257)
+
+
+def test_kernel_wrappers_interpret_only_on_the_cpu(monkeypatch):
+    """The TPU compiles the kernels, the CPU interprets them, and any
+    other backend is refused rather than interpreted in silence."""
+    from repro.kernels import mode
+    assert mode.interpret_mode() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(mode.jax, "default_backend", lambda: "tpu")
+    assert mode.interpret_mode() is False
+    monkeypatch.setattr(mode.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        mode.interpret_mode()
+
+
 def test_ragged_decode_block_invariance():
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(ks[0], (2, 1, 4, 16), jnp.float32)

@@ -1,0 +1,13 @@
+"""Share of the traced window in which the device was idle while the host
+was inside a decode step or a fused horizon (``engine.decode``,
+``engine.horizon`` and their ``.put``, ``.launch``, ``.sync``, ``.emit``
+spans), %."""
+
+
+def read(readings, config, peaks):
+    tr = readings.get("trace")
+    if not tr or "idle_by_engine_span" not in tr or tr["window_s"] <= 0:
+        return None
+    idle = sum(s for span, s in tr["idle_by_engine_span"].items()
+               if span.startswith(("engine.decode", "engine.horizon")))
+    return 100.0 * idle / tr["window_s"]

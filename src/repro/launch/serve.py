@@ -26,6 +26,7 @@ import dataclasses
 import time
 import warnings
 
+import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_config, get_smoke_config
@@ -438,9 +439,12 @@ def main(argv=None):
                          "sheds new arrivals, lowest priority first "
                          "(default 0 = unlimited)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write a Chrome/Perfetto trace-event JSON of "
-                         "the run (open at https://ui.perfetto.dev; "
-                         "DESIGN.md §14)")
+                    help="a fleet (--workers > 1) writes a "
+                         "Chrome/Perfetto trace-event JSON of the run in "
+                         "virtual time (open at https://ui.perfetto.dev); "
+                         "a single engine writes a JAX profiler trace "
+                         "directory at PATH, its wall-clock engine spans "
+                         "beside the device's programs (DESIGN.md §14)")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the unified metrics registry "
                          "(counters/gauges/quantile sketches keyed by "
@@ -498,12 +502,17 @@ def main(argv=None):
                      migrations=migrations)
     if plan.n_workers > 1:
         run_fleet(cfg, client, args)
+        if args.trace_out:
+            obs.recorder.dump(args.trace_out)
+            print(f"trace: {len(obs.recorder.events)} events -> "
+                  f"{args.trace_out} (open at https://ui.perfetto.dev)")
+    elif args.trace_out:
+        with jax.profiler.trace(args.trace_out):
+            run_single(cfg, client, args)
+        print(f"trace: JAX profiler trace -> {args.trace_out} (read with "
+              f"jax.profiler.ProfileData or TensorBoard's profile plugin)")
     else:
         run_single(cfg, client, args)
-    if args.trace_out:
-        obs.recorder.dump(args.trace_out)
-        print(f"trace: {len(obs.recorder.events)} events -> "
-              f"{args.trace_out} (open at https://ui.perfetto.dev)")
     if args.metrics_out:
         obs.metrics.dump(args.metrics_out)
         print(f"metrics: {len(obs.metrics.names())} series -> "

@@ -103,6 +103,7 @@ def _norm_bwd(kind, res, dy):
 _norm_core.defvjp(_norm_fwd, _norm_bwd)
 
 
+@jax.named_scope("norm")
 def apply_norm(p, x, kind: str, eps: float = 1e-6):
     bias = p.get("bias")
     if bias is None:
@@ -187,6 +188,7 @@ def ffn_specs(cfg: ArchConfig, d_ff: Optional[int] = None):
             "w_down": ParamSpec((f, d), ("mlp", "embed"))}
 
 
+@jax.named_scope("mlp")
 def apply_ffn(p, x, act: str):
     dt = x.dtype
     if act in ("swiglu", "geglu"):
@@ -212,6 +214,7 @@ def embed_specs(cfg: ArchConfig):
     return out
 
 
+@jax.named_scope("embed")
 def embed_tokens(p, tokens, cfg: ArchConfig):
     emb = jnp.take(p["tok"], tokens, axis=0)
     return emb.astype(cfg.compute_dtype)

@@ -231,18 +231,21 @@ class Model:
         pads ragged prompts up to a shared length bucket, and causal
         attention makes trailing padding invisible to position
         ``last_index[b]`` (bit-identical to an exact-length prefill)."""
-        cfg = self.cfg
         h, new_cache, _ = self.forward(params, batch, mode="prefill",
                                        cache=cache, shard_fn=shard_fn,
                                        remat=False, skip_future=skip_future)
-        head = head_matrix(params["embed"], cfg)
         if last_index is None:
             last = h[:, -1, :]
         else:
             b = h.shape[0]
             last = h[jnp.arange(b), jnp.asarray(last_index, jnp.int32), :]
-        logits = (last @ head.astype(last.dtype)).astype(jnp.float32)
-        return logits, new_cache
+        return self._lm_head(params, last), new_cache
+
+    @jax.named_scope("lm_head")
+    def _lm_head(self, params, h):
+        """(B, d) hidden -> (B, V) float32 logits."""
+        head = head_matrix(params["embed"], self.cfg)
+        return (h @ head.astype(h.dtype)).astype(jnp.float32)
 
     def decode_step(self, params, cache, tokens=None, embeds=None,
                     shard_fn=lambda a, *n: a, use_ragged_kernel=False,
@@ -283,9 +286,7 @@ class Model:
                                        remat=False,
                                        use_ragged_kernel=use_ragged_kernel,
                                        decode_write_mask=write_mask)
-        head = head_matrix(params["embed"], cfg)
-        logits = (h[:, 0, :] @ head.astype(h.dtype)).astype(jnp.float32)
-        return logits, new_cache
+        return self._lm_head(params, h[:, 0, :]), new_cache
 
     def decode_horizon(self, params, cache, state, *, horizon: int,
                        max_len: int, use_ragged_kernel=False):

@@ -149,6 +149,7 @@ class BlockCtx:
     #                                   (DESIGN.md §13); None = contiguous
 
 
+@jax.named_scope("kv_write")
 def _attn_cache_write(cache, k_new, v_new, idx, window: int, rolling: bool,
                       write_mask=None):
     idx = jnp.asarray(idx)
@@ -175,6 +176,7 @@ def _attn_cache_write(cache, k_new, v_new, idx, window: int, rolling: bool,
     return {"k": k, "v": v}
 
 
+@jax.named_scope("kv_write")
 def _attn_cache_write_paged(cache, k_new, v_new, idx, page_table,
                             write_mask=None):
     """Scatter one decode step's k/v into a PAGED cache.
@@ -235,6 +237,7 @@ def _decode_valid_mask(smax, idx, window: int, rolling: bool):
     return j <= idx
 
 
+@jax.named_scope("attn")
 def _self_attention(p, h, ctx: BlockCtx, window: int, cache):
     cfg = ctx.cfg
     q = project_q(p, h, cfg)

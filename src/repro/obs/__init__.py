@@ -4,7 +4,8 @@ Two halves, bundled by :class:`Observability`:
 
 * :mod:`repro.obs.trace` — the flight recorder: per-request lifecycle
   spans and resource instant events in virtual time, exported as
-  Chrome trace-event / Perfetto JSON;
+  Chrome trace-event / Perfetto JSON; and ``host_span``, the engine's
+  wall-clock spans on the JAX profiler's trace;
 * :mod:`repro.obs.metrics` — the metrics registry: named counters /
   gauges / histograms keyed by (resource axis, sharing group, worker),
   histograms backed by a deterministic streaming quantile sketch.
@@ -17,7 +18,7 @@ serving hot path pays nothing unless a caller opts in via
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                MetricsWindow, NOOP_REGISTRY, QuantileSketch,
                                quantile)
-from repro.obs.trace import (FlightRecorder, NoopRecorder, NOOP_RECORDER,
+from repro.obs.trace import (host_span, FlightRecorder, NoopRecorder, NOOP_RECORDER,
                              Observability, NOOP_OBS, enabled_obs,
                              PID_FLEET, PID_RESOURCES, PID_REQUESTS,
                              TID_ROUTER, TID_WORKER0, TID_CHANNEL0,
@@ -27,7 +28,7 @@ from repro.obs.validate import validate_trace
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "MetricsWindow",
     "NOOP_REGISTRY", "QuantileSketch", "quantile",
-    "FlightRecorder", "NoopRecorder", "NOOP_RECORDER",
+    "host_span", "FlightRecorder", "NoopRecorder", "NOOP_RECORDER",
     "Observability", "NOOP_OBS", "enabled_obs",
     "PID_FLEET", "PID_RESOURCES", "PID_REQUESTS",
     "TID_ROUTER", "TID_WORKER0", "TID_CHANNEL0", "TID_PAGES0",

@@ -1,13 +1,24 @@
-"""Flight recorder: structured spans over the serving stack's virtual
-time, exported as Chrome trace-event JSON (Perfetto-loadable)
-(DESIGN.md §14).
+"""Tracing for the serving stack (DESIGN.md §14), on two clocks.
 
-The recorder captures every request's lifecycle — arrive → route →
-queue-wait → admit/prefill → decode steps/horizons → retire → deliver —
-plus instant events for replan transitions, page-pool deferrals, jit
-compiles, and channel-lock waits.  All timestamps are the fabric's
-VIRTUAL nanoseconds (`serve.fabric.router`), so two runs of the same
-seed export bit-identical traces; no wall clock ever enters an event.
+Virtual time belongs to the fleet's :class:`FlightRecorder`; wall-clock
+time belongs to :func:`host_span`.
+
+``host_span`` opens a ``jax.profiler.TraceAnnotation`` (a TraceMe), so a
+span lands in the same ``.xplane.pb`` as the device's programs and ops,
+on the clock the profiler aligns with the device.  The serving engine
+opens one at each boundary where the host does work or waits (admission
+packing, host-to-device copies, dispatch, readback, bookkeeping).  With
+no profiler collecting, a span costs one context manager and its
+arguments are never built.
+
+The flight recorder captures every fleet request's lifecycle — arrive →
+route → queue-wait → admit/prefill → decode steps/horizons → retire →
+deliver — plus instant events for replan transitions, page-pool
+deferrals, jit compiles, and channel-lock waits, exported as Chrome
+trace-event JSON (Perfetto-loadable).  All its timestamps are the
+fabric's VIRTUAL nanoseconds (`serve.fabric.router`), so two runs of the
+same seed export bit-identical traces; no wall clock ever enters an
+event.
 
 Track layout (Chrome's pid/tid hierarchy, one Perfetto track each):
 
@@ -37,7 +48,9 @@ from __future__ import annotations
 import json
 from typing import Dict, List, Optional
 
-__all__ = ["FlightRecorder", "NoopRecorder", "NOOP_RECORDER",
+import jax
+
+__all__ = ["host_span", "FlightRecorder", "NoopRecorder", "NOOP_RECORDER",
            "Observability", "NOOP_OBS", "enabled_obs",
            "PID_FLEET", "PID_RESOURCES", "PID_REQUESTS",
            "TID_ROUTER", "TID_WORKER0", "TID_CHANNEL0", "TID_PAGES0"]
@@ -50,6 +63,19 @@ TID_ROUTER = 0
 TID_WORKER0 = 100        # worker w -> tid TID_WORKER0 + w
 TID_CHANNEL0 = 200       # channel q -> tid TID_CHANNEL0 + q
 TID_PAGES0 = 300         # worker w's page pool -> tid TID_PAGES0 + w
+
+
+def host_span(name: str, **args):
+    """A wall-clock span named ``name`` on the profiler's host plane.
+
+    ``args`` become the span's arguments (``name#key=value#`` in the
+    trace) only while a profiler is collecting; a value may be a
+    zero-argument callable, called only then.  Use as a context
+    manager."""
+    if args and jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(
+            name, **{k: v() if callable(v) else v for k, v in args.items()})
+    return jax.profiler.TraceAnnotation(name)
 
 
 def _ts(t_ns: float) -> float:

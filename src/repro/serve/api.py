@@ -50,7 +50,7 @@ from repro.core.adapt import Replanner, WindowStats
 from repro.core.plan import EndpointPlan, Hints, SharingVector, as_plan
 from repro.models.model import Model
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import (NOOP_OBS, Observability, PID_REQUESTS)
+from repro.obs.trace import NOOP_OBS, Observability
 from repro.serve.engine import ContinuousEngine, Request, ServeEngine
 from repro.serve.fabric.faults import FaultPlan
 from repro.serve.fabric.placement import POLICIES
@@ -141,7 +141,8 @@ class ServeClient:
         self.plan_repository = plan_repository
         #: observability bundle (DESIGN.md §14): defaults to the no-op
         #: recorder/registry; ``connect(..., obs=enabled_obs())`` records
-        #: every run's spans + metrics for --trace-out / --metrics-out
+        #: a fleet's virtual-time spans and every run's metrics (a single
+        #: engine's wall-clock spans go to the JAX profiler instead)
         self.obs = obs if obs is not None else NOOP_OBS
         self.executor = plan.resolved_executor
         if (faults is not None or recovery is not None
@@ -392,32 +393,10 @@ class ServeClient:
                     self._apply_vector(vec)
                     self.transitions.append((eng._step_no, vec))
         eng.publish_metrics(reg, worker=0)
-        if self.obs.tracing:
-            self._record_engine_spans(out)
         if adapt is not None and adapt.vector != self.plan.vector:
             self.plan = dataclasses.replace(self.plan, preset=None,
                                             vector=adapt.vector)
         return out
-
-    def _record_engine_spans(self, out: Dict[int, List[int]]) -> None:
-        """Post-hoc request-lifecycle spans for the single continuous
-        engine: it runs closed-loop on the host clock, so spans are laid
-        out on the engine's deterministic step counter scaled by the
-        fabric cost model's step cost — the same virtual-ns axis fleet
-        traces use (wall clock never enters the trace)."""
-        rec = self.obs.recorder
-        base = FabricCosts().t_step_base_ns
-        eng = self.engine
-        for rid in sorted(out):
-            a = eng.admit_steps.get(rid)
-            r = eng.retire_steps.get(rid)
-            if a is None or r is None:
-                continue
-            rec.begin(PID_REQUESTS, "request", rid, a * base,
-                      args={"admit_step": a})
-            rec.end(PID_REQUESTS, "request", rid, r * base,
-                    args={"retire_step": r,
-                          "new_tokens": len(out[rid])})
 
     def _build_workers(self):
         plan = self.plan

@@ -54,8 +54,33 @@ from repro.configs.base import ArchConfig
 from repro.core.plan import EndpointPlan, SharingVector
 from repro.models.model import Model
 from repro.models.transformer import ragged_kv_block
+from repro.obs.metrics import Histogram
+from repro.obs.trace import host_span
 from repro.serve.pages import PagePool, sentinel
 from repro.serve.slots import SlotPool, _coerce_level
+
+# Wall-clock spans of ``ContinuousEngine`` (``obs.host_span``), one at each
+# boundary where the host works or waits.  ``engine.admit`` is one
+# admission round (args ``rids``, ``rows``, ``bucket``); ``engine.decode``
+# one K=1 step, ``engine.horizon`` one fused K-step horizon.  Children:
+# ``pack`` numpy packing, ``put`` host-to-device copies, ``launch`` the
+# program dispatch, ``sync`` the blocking readback, ``bind`` / ``emit``
+# the host bookkeeping after it.
+SPAN_ADMIT = "engine.admit"
+SPAN_ADMIT_PACK = "engine.admit.pack"
+SPAN_ADMIT_PUT = "engine.admit.put"
+SPAN_ADMIT_LAUNCH = "engine.admit.launch"
+SPAN_ADMIT_SYNC = "engine.admit.sync"
+SPAN_ADMIT_BIND = "engine.admit.bind"
+SPAN_DECODE = "engine.decode"
+SPAN_DECODE_PUT = "engine.decode.put"
+SPAN_DECODE_LAUNCH = "engine.decode.launch"
+SPAN_DECODE_SYNC = "engine.decode.sync"
+SPAN_DECODE_EMIT = "engine.decode.emit"
+SPAN_HORIZON = "engine.horizon"
+SPAN_HORIZON_LAUNCH = "engine.horizon.launch"
+SPAN_HORIZON_SYNC = "engine.horizon.sync"
+SPAN_HORIZON_EMIT = "engine.horizon.emit"
 
 
 @dataclasses.dataclass
@@ -90,6 +115,8 @@ class Request:
     output: Optional[list] = None      # filled by the engine
     kv: Optional[KVHandoff] = None     # imported cache: admission merges
     #                                    it instead of running a prefill
+    arrival_s: Optional[float] = None  # caller's arrival, time.perf_counter
+    #                                    (ContinuousEngine.submit stamps it)
 
 
 class ServeEngine:
@@ -219,10 +246,24 @@ def _shared_steps(cfg: ArchConfig, use_ragged_kernel: bool,
 def _shared_steps_cached(cfg: ArchConfig, use_ragged_kernel: bool,
                          exec_group: int) -> SharedSteps:
     model = Model(cfg)
-    decode = jax.jit(
-        lambda p, c, t: model.decode_step(
-            p, c, tokens=t, use_ragged_kernel=use_ragged_kernel))
-    prefill = jax.jit(lambda p, b, c: model.prefill(p, b, c))
+
+    # named functions, so each program is ``jit_<name>`` in a profile
+    def decode_step(p, c, t):
+        return model.decode_step(p, c, tokens=t,
+                                 use_ragged_kernel=use_ragged_kernel)
+
+    def prefill(p, b, c):
+        return model.prefill(p, b, c)
+
+    def decode_horizon(p, c, s, k, ml):
+        return model.decode_horizon(p, c, s, horizon=k, max_len=ml,
+                                    use_ragged_kernel=use_ragged_kernel)
+
+    def merge(full, one, slot):
+        return _scatter_slot(full, one, slot)
+
+    def merge_paged(full, one, slot, pt_slot):
+        return _scatter_slot_paged(full, one, slot, pt_slot)
 
     def admit_packed(p, full, state, toks, last_index, slot_ids, valid,
                      lengths, remaining, eos, has_eos, max_len):
@@ -275,19 +316,14 @@ def _shared_steps_cached(cfg: ArchConfig, use_ragged_kernel: bool,
         }
         return cache, state
 
-    merge = jax.jit(_scatter_slot)
-    admit_packed = jax.jit(admit_packed, static_argnums=(11,))
-    merge_paged = jax.jit(_scatter_slot_paged)
-    admit_packed_paged = jax.jit(admit_packed_paged, static_argnums=(12,))
-    horizon = jax.jit(
-        lambda p, c, s, k, ml: model.decode_horizon(
-            p, c, s, horizon=k, max_len=ml,
-            use_ragged_kernel=use_ragged_kernel),
-        static_argnums=(3, 4))
-    return SharedSteps(model=model, decode=decode, prefill=prefill,
-                       merge=merge, admit_packed=admit_packed,
-                       horizon=horizon, merge_paged=merge_paged,
-                       admit_packed_paged=admit_packed_paged)
+    return SharedSteps(
+        model=model, decode=jax.jit(decode_step),
+        prefill=jax.jit(prefill), merge=jax.jit(merge),
+        admit_packed=jax.jit(admit_packed, static_argnums=(11,)),
+        horizon=jax.jit(decode_horizon, static_argnums=(3, 4)),
+        merge_paged=jax.jit(merge_paged),
+        admit_packed_paged=jax.jit(admit_packed_paged,
+                                   static_argnums=(12,)))
 
 
 def _scatter_slot(full, one, slot):
@@ -534,11 +570,18 @@ class ContinuousEngine:
         self.admit_order: List[int] = []
         # decode_steps: token steps; decode_calls: jitted executables
         # dispatched; host_syncs: blocking device->host transfers;
-        # busy_slot_steps / slot_steps is the pool's occupancy
+        # busy_slot_steps / slot_steps is the pool's occupancy;
+        # admit_positions: token positions the admission prefills computed
+        # (rows x bucket per packed round, the prompt on the exact-length
+        # path), of which admit_real_tokens were prompt tokens
         self.stats = {"decode_steps": 0, "decode_calls": 0,
                       "slot_steps": 0, "busy_slot_steps": 0,
                       "prefills": 0, "prefilled_requests": 0,
-                      "host_syncs": 0, "regroups": 0}
+                      "host_syncs": 0, "regroups": 0,
+                      "admit_positions": 0, "admit_real_tokens": 0}
+        # seconds from each request's arrival_s to the start of the
+        # admission round that took it (host clock)
+        self.queue_wait = Histogram()
         self.use_ragged_kernel = use_ragged_kernel
         self.exec_group = exec_group
         self._steps = _shared_steps(cfg, use_ragged_kernel, exec_group)
@@ -618,6 +661,8 @@ class ContinuousEngine:
             raise ValueError(
                 f"prompt of {len(req.prompt)} tokens cannot fit max_len="
                 f"{self.max_len}")
+        if req.arrival_s is None:
+            req.arrival_s = time.perf_counter()
         self.queue.append(req)
 
     # ----- slot lifecycle -------------------------------------------------
@@ -639,33 +684,42 @@ class ContinuousEngine:
     def _admit(self, cache, slot: int, req: Request):
         """Prefill ``req`` alone and scatter its cache into ``slot`` (the
         exact-length path: one jit specialization per prompt length)."""
-        prompt = jnp.asarray(np.asarray(req.prompt)[None], jnp.int32)
-        one = self.model.init_cache(1, self.max_len)
-        logits, one = self._prefill(self.params, {"tokens": prompt}, one)
-        if self.page_pool is not None:
-            # the batch-1 prefill is contiguous (prompts are dense); the
-            # page scatter splits it into the slot's pages
-            cache = self._steps.merge_paged(
-                cache, one, jnp.asarray(slot, jnp.int32),
-                jnp.asarray(self._pt[slot]))
-        else:
-            cache = self._merge(cache, one, jnp.asarray(slot, jnp.int32))
-        first = int(jnp.argmax(logits, -1)[0])
-        self._bind(slot, req, first)
-        if self._dev_state is not None:
-            s = self._dev_state
-            self._dev_state = {
-                "tok": s["tok"].at[slot].set(first),
-                "remaining": s["remaining"].at[slot].set(
-                    req.max_new_tokens),
-                "finished": s["finished"].at[slot].set(False),
-                "eos": s["eos"].at[slot].set(self._eos_id[slot]),
-                "has_eos": s["has_eos"].at[slot].set(
-                    bool(self._has_eos[slot])),
-            }
+        with host_span(SPAN_ADMIT_PUT):
+            prompt = jnp.asarray(np.asarray(req.prompt)[None], jnp.int32)
+            slot_id = jnp.asarray(slot, jnp.int32)
+            pt_slot = (jnp.asarray(self._pt[slot])
+                       if self.page_pool is not None else None)
+        with host_span(SPAN_ADMIT_LAUNCH):
+            one = self.model.init_cache(1, self.max_len)
+            logits, one = self._prefill(self.params, {"tokens": prompt},
+                                        one)
+            if self.page_pool is not None:
+                # the batch-1 prefill is contiguous (prompts are dense);
+                # the page scatter splits it into the slot's pages
+                cache = self._steps.merge_paged(cache, one, slot_id,
+                                                pt_slot)
+            else:
+                cache = self._merge(cache, one, slot_id)
+        with host_span(SPAN_ADMIT_SYNC):
+            first = int(jnp.argmax(logits, -1)[0])
+        with host_span(SPAN_ADMIT_BIND):
+            self._bind(slot, req, first)
+            if self._dev_state is not None:
+                s = self._dev_state
+                self._dev_state = {
+                    "tok": s["tok"].at[slot].set(first),
+                    "remaining": s["remaining"].at[slot].set(
+                        req.max_new_tokens),
+                    "finished": s["finished"].at[slot].set(False),
+                    "eos": s["eos"].at[slot].set(self._eos_id[slot]),
+                    "has_eos": s["has_eos"].at[slot].set(
+                        bool(self._has_eos[slot])),
+                }
         self.stats["prefills"] += 1
         self.stats["prefilled_requests"] += 1
         self.stats["host_syncs"] += 1
+        self.stats["admit_positions"] += len(req.prompt)
+        self.stats["admit_real_tokens"] += len(req.prompt)
         return cache
 
     def _host_state(self):
@@ -690,51 +744,55 @@ class ContinuousEngine:
         In fused-horizon mode the round is fire-and-forget (no sync)."""
         n = self.n_slots
         bucket = self._bucket_of(max(len(r.prompt) for _, r in batch))
-        toks = np.zeros((n, bucket), np.int32)
-        last = np.zeros((n,), np.int32)
-        slot_ids = np.zeros((n,), np.int32)
-        valid = np.zeros((n,), bool)
-        lengths = np.zeros((n,), np.int32)
-        remaining = np.zeros((n,), np.int32)
-        eos = np.full((n,), -1, np.int32)
-        has_eos = np.zeros((n,), bool)
-        for j, (slot, req) in enumerate(batch):
-            ln = len(req.prompt)
-            toks[j, :ln] = req.prompt
-            last[j] = ln - 1
-            slot_ids[j] = slot
-            valid[j] = True
-            lengths[j] = ln
-            remaining[j] = req.max_new_tokens
-            eos[j] = -1 if req.eos_id is None else req.eos_id
-            has_eos[j] = req.eos_id is not None
+        with host_span(SPAN_ADMIT_PACK):
+            toks = np.zeros((n, bucket), np.int32)
+            last = np.zeros((n,), np.int32)
+            slot_ids = np.zeros((n,), np.int32)
+            valid = np.zeros((n,), bool)
+            lengths = np.zeros((n,), np.int32)
+            remaining = np.zeros((n,), np.int32)
+            eos = np.full((n,), -1, np.int32)
+            has_eos = np.zeros((n,), bool)
+            for j, (slot, req) in enumerate(batch):
+                ln = len(req.prompt)
+                toks[j, :ln] = req.prompt
+                last[j] = ln - 1
+                slot_ids[j] = slot
+                valid[j] = True
+                lengths[j] = ln
+                remaining[j] = req.max_new_tokens
+                eos[j] = -1 if req.eos_id is None else req.eos_id
+                has_eos[j] = req.eos_id is not None
         fused = self._dev_state is not None
-        state = self._dev_state if fused else self._host_state()
-        if self.page_pool is not None:
-            cache, state = self._steps.admit_packed_paged(
-                self.params, cache, state, jnp.asarray(toks),
-                jnp.asarray(last), jnp.asarray(slot_ids),
-                jnp.asarray(valid), jnp.asarray(lengths),
-                jnp.asarray(remaining), jnp.asarray(eos),
-                jnp.asarray(has_eos), jnp.asarray(self._pt), self.max_len)
-        else:
-            cache, state = self._steps.admit_packed(
-                self.params, cache, state, jnp.asarray(toks),
-                jnp.asarray(last), jnp.asarray(slot_ids),
-                jnp.asarray(valid), jnp.asarray(lengths),
-                jnp.asarray(remaining), jnp.asarray(eos),
-                jnp.asarray(has_eos), self.max_len)
+        with host_span(SPAN_ADMIT_PUT):
+            state = self._dev_state if fused else self._host_state()
+            args = [jnp.asarray(a) for a in (toks, last, slot_ids, valid,
+                                             lengths, remaining, eos,
+                                             has_eos)]
+            if self.page_pool is not None:
+                args.append(jnp.asarray(self._pt))
+        with host_span(SPAN_ADMIT_LAUNCH):
+            program = (self._steps.admit_packed_paged
+                       if self.page_pool is not None
+                       else self._steps.admit_packed)
+            cache, state = program(self.params, cache, state, *args,
+                                   self.max_len)
         if fused:
             self._dev_state = state
-            for slot, req in batch:
-                self._bind(slot, req)
+            with host_span(SPAN_ADMIT_BIND):
+                for slot, req in batch:
+                    self._bind(slot, req)
         else:
-            first = np.asarray(state["tok"])              # one sync
-            for j, (slot, req) in enumerate(batch):
-                self._bind(slot, req, int(first[slot_ids[j]]))
+            with host_span(SPAN_ADMIT_SYNC):
+                first = np.asarray(state["tok"])          # one sync
+            with host_span(SPAN_ADMIT_BIND):
+                for j, (slot, req) in enumerate(batch):
+                    self._bind(slot, req, int(first[slot_ids[j]]))
             self.stats["host_syncs"] += 1
         self.stats["prefills"] += 1
         self.stats["prefilled_requests"] += len(batch)
+        self.stats["admit_positions"] += n * bucket
+        self.stats["admit_real_tokens"] += int(lengths.sum())
         return cache
 
     # ----- prefill/decode disaggregation (DESIGN.md §17) -----------------
@@ -891,6 +949,8 @@ class ContinuousEngine:
                            ("host_syncs", "execs"),
                            ("prefills", "execs"),
                            ("prefilled_requests", "execs"),
+                           ("admit_positions", "execs"),
+                           ("admit_real_tokens", "execs"),
                            ("slot_steps", "slots"),
                            ("busy_slot_steps", "slots"),
                            ("regroups", "slots")):
@@ -901,6 +961,12 @@ class ContinuousEngine:
                          worker=worker).set_total(self.compile_count())
         registry.gauge("engine.queue_depth", axis="channels",
                        worker=worker).set(len(self.queue))
+        if registry.enabled:
+            # absolute, like set_total: the registry's sketch mirrors the
+            # engine's (window it with snapshot() / minus())
+            registry.histogram("engine.queue_wait_s", axis="slots",
+                               worker=worker).sketch = \
+                self.queue_wait.sketch.snapshot()
         if self.page_pool is not None:
             self.page_pool.publish_metrics(registry, axis="pages",
                                            worker=worker)
@@ -1150,21 +1216,26 @@ class ContinuousEngine:
             self.stats["page_hwm"] = self.page_pool.hwm
         if not batch:
             return 0
+        t_round = time.perf_counter()
+        for _, req in batch:
+            self.queue_wait.observe(t_round - req.arrival_s)
         kv_batch = [(s, r) for s, r in batch if r.kv is not None]
         batch = [(s, r) for s, r in batch if r.kv is None]
-        for slot, req in kv_batch:      # cache merge, no forward pass
-            self._cache = self._admit_handoff(self._cache, slot, req)
-        if self.prefill_buckets:
-            cap = self.prefill_buckets[-1]
-            fit = [(s, r) for s, r in batch if len(r.prompt) <= cap]
+        cap = self.prefill_buckets[-1] if self.prefill_buckets else -1
+        fit = [(s, r) for s, r in batch if len(r.prompt) <= cap]
+        with host_span(
+                SPAN_ADMIT, rows=len(batch) + len(kv_batch),
+                rids=lambda: " ".join(str(r.rid)    # "," splits args
+                                      for _, r in kv_batch + batch),
+                bucket=lambda: self._bucket_of(
+                    max(len(r.prompt) for _, r in fit)) if fit else 0):
+            for slot, req in kv_batch:      # cache merge, no forward pass
+                self._cache = self._admit_handoff(self._cache, slot, req)
             if fit:
                 self._cache = self._admit_batch(self._cache, fit)
             for slot, req in batch:
                 if len(req.prompt) > cap:
                     self._cache = self._admit(self._cache, slot, req)
-        else:
-            for slot, req in batch:
-                self._cache = self._admit(self._cache, slot, req)
         return len(batch) + len(kv_batch)
 
     def step(self) -> List[Request]:
@@ -1178,8 +1249,15 @@ class ContinuousEngine:
         active = [i for i, r in enumerate(self._slot_req) if r is not None]
         if not active:
             return []
-        logits, self._cache = self._decode(self.params, self._cache,
-                                           jnp.asarray(self._next_tok))
+        with host_span(SPAN_DECODE, rows=len(active)):
+            return self._step_one(active)
+
+    def _step_one(self, active: List[int]) -> List[Request]:
+        with host_span(SPAN_DECODE_PUT):
+            toks = jnp.asarray(self._next_tok)
+        with host_span(SPAN_DECODE_LAUNCH):
+            logits, self._cache = self._decode(self.params, self._cache,
+                                               toks)
         self.stats["decode_steps"] += 1
         self.stats["decode_calls"] += 1
         self.stats["host_syncs"] += 1
@@ -1187,23 +1265,25 @@ class ContinuousEngine:
         self.stats["busy_slot_steps"] += len(active)
         self._step_no += 1
         produced = self._next_tok.copy()
-        # np.array (copy): admission writes the prefill token in-place
-        nxt = np.array(jnp.argmax(logits, -1), np.int32)
+        with host_span(SPAN_DECODE_SYNC):
+            # np.array (copy): admission writes the prefill token in-place
+            nxt = np.array(jnp.argmax(logits, -1), np.int32)
         self._pos += 1       # every row's cache index advanced
         retired: List[Request] = []
-        for i in active:
-            r = self._slot_req[i]
-            r.output.append(int(produced[i]))
-            self._remaining[i] -= 1
-            finished = (self._remaining[i] <= 0
-                        or (r.eos_id is not None
-                            and int(nxt[i]) == r.eos_id))
-            if not finished and self._pos[i] >= self.max_len - 1:
-                r.output.append(int(nxt[i]))   # budget exhausted
-                finished = True
-            if finished:
-                self._retire(i)
-                retired.append(r)
+        with host_span(SPAN_DECODE_EMIT):
+            for i in active:
+                r = self._slot_req[i]
+                r.output.append(int(produced[i]))
+                self._remaining[i] -= 1
+                finished = (self._remaining[i] <= 0
+                            or (r.eos_id is not None
+                                and int(nxt[i]) == r.eos_id))
+                if not finished and self._pos[i] >= self.max_len - 1:
+                    r.output.append(int(nxt[i]))   # budget exhausted
+                    finished = True
+                if finished:
+                    self._retire(i)
+                    retired.append(r)
         self._next_tok = nxt
         return retired
 
@@ -1213,11 +1293,17 @@ class ContinuousEngine:
         the horizon's single host sync (the batched doorbell)."""
         if self.n_active == 0:
             return []
+        with host_span(SPAN_HORIZON, rows=self.n_active):
+            return self._horizon_once()
+
+    def _horizon_once(self) -> List[Request]:
         k = self.decode_horizon
-        self._cache, self._dev_state, trace = self._steps.horizon(
-            self.params, self._cache, self._dev_state, k, self.max_len)
-        # ONE blocking transfer drains the whole K-step token trace
-        trace = jax.device_get(trace)
+        with host_span(SPAN_HORIZON_LAUNCH):
+            self._cache, self._dev_state, trace = self._steps.horizon(
+                self.params, self._cache, self._dev_state, k, self.max_len)
+        with host_span(SPAN_HORIZON_SYNC):
+            # ONE blocking transfer drains the whole K-step token trace
+            trace = jax.device_get(trace)
         # the horizon exits early once every slot drains, so the executed
         # step count comes from the trace, not from K
         executed = int(trace["live"].any(axis=1).sum())
@@ -1226,20 +1312,21 @@ class ContinuousEngine:
         self.stats["host_syncs"] += 1
         self.stats["slot_steps"] += executed * self.n_slots
         retired: List[Request] = []
-        for s in range(k):
-            row_live = trace["live"][s]
-            if not row_live.any():
-                break     # liveness is monotone within a horizon
-            self._step_no += 1
-            self.stats["busy_slot_steps"] += int(row_live.sum())
-            for i in np.nonzero(row_live)[0]:
-                r = self._slot_req[i]
-                r.output.append(int(trace["tok"][s, i]))
-                if trace["bonus"][s, i]:
-                    r.output.append(int(trace["bonus_tok"][s, i]))
-                if trace["retired"][s, i]:
-                    self._retire(i)
-                    retired.append(r)
+        with host_span(SPAN_HORIZON_EMIT):
+            for s in range(k):
+                row_live = trace["live"][s]
+                if not row_live.any():
+                    break     # liveness is monotone within a horizon
+                self._step_no += 1
+                self.stats["busy_slot_steps"] += int(row_live.sum())
+                for i in np.nonzero(row_live)[0]:
+                    r = self._slot_req[i]
+                    r.output.append(int(trace["tok"][s, i]))
+                    if trace["bonus"][s, i]:
+                        r.output.append(int(trace["bonus_tok"][s, i]))
+                    if trace["retired"][s, i]:
+                        self._retire(i)
+                        retired.append(r)
         self._pos += executed    # every row's cache index advanced as one
         return retired
 

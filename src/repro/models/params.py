@@ -9,16 +9,29 @@ a code change.
 
 ``materialize`` builds real arrays, ``abstract`` builds ShapeDtypeStructs
 (for eval_shape-free dry runs), ``axes_tree`` extracts the logical axes.
+``serving_params`` binds a tree for serving in the compute dtype.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs.trace import host_span
+
+#: Wall-clock span of the one jitted cast ``serving_params`` makes (args
+#: ``leaves``, ``bytes_before``, ``bytes_after``).
+SPAN_BIND_WEIGHTS = "engine.bind_weights"
+
+#: Block kinds whose subtree reads gates, biases and recurrences in
+#: float32 (``recurrent.py``, ``xlstm.py``): a compute-dtype copy of it
+#: would change their numbers, so serving keeps its stored dtype.
+FLOAT32_READ_KINDS = ("rglru", "mlstm", "slstm")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,3 +106,59 @@ def axes_tree(spec_tree):
 def n_params(spec_tree) -> int:
     return sum(int(np.prod(s.shape))
                for s in jax.tree.leaves(spec_tree, is_leaf=is_spec))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _astype(leaves, dtype):
+    return [a.astype(dtype) for a in leaves]
+
+
+def _read_in_compute_dtype(params, plan):
+    """Tree of bools shaped like ``params``: True where the decoder only
+    ever reads the leaf through ``.astype(compute_dtype)`` (embedding,
+    untied head, final norm, and every block subtree but the recurrent
+    ones).  Stacks the serving engines never run (an encoder) stay
+    False."""
+    served = ("embed", "final_norm", "decoder")
+    mask = {k: jax.tree.map(lambda _: k in served, v)
+            for k, v in params.items()}
+    dec = mask["decoder"]
+    for blocks, descs in ((dec["prefix"], plan.prefix),
+                          (dec["body"], plan.period)):
+        for blk, desc in zip(blocks, descs):
+            if desc.kind in FLOAT32_READ_KINDS:
+                blk[desc.kind] = jax.tree.map(lambda _: False,
+                                              blk[desc.kind])
+    return mask
+
+
+def serving_params(params, cfg, plan):
+    """Bind ``params`` for serving: -> ``(tree, binding)``.
+
+    Every float32 leaf the decoder reads only through
+    ``.astype(cfg.compute_dtype)`` is replaced by its compute-dtype copy,
+    made in one jitted call, so no serving program converts weights on
+    each step.  The model read each of them only through that cast, so
+    no number it computes changes.  Other leaves, and leaves already
+    in the compute dtype, are returned as they are: binding a bound tree
+    makes no second copy.  ``plan`` is the model's ``LayerPlan``.
+    ``binding`` counts the cast ``leaves`` and their ``bytes_before`` and
+    ``bytes_after``."""
+    dt = jnp.dtype(cfg.compute_dtype)
+    leaves, treedef = jax.tree.flatten(params)
+    idx = []
+    if leaves and dt != jnp.float32:
+        wanted = treedef.flatten_up_to(_read_in_compute_dtype(params, plan))
+        idx = [i for i, (a, w) in enumerate(zip(leaves, wanted))
+               if w and a.dtype == jnp.float32]
+    binding = {"leaves": len(idx),
+               "bytes_before": sum(leaves[i].nbytes for i in idx),
+               "bytes_after": sum(leaves[i].size * dt.itemsize
+                                  for i in idx)}
+    if not idx:
+        return params, binding
+    with host_span(SPAN_BIND_WEIGHTS, **binding):
+        cast = _astype(tuple(leaves[i] for i in idx), dt)
+    for i, a in zip(idx, cast):
+        leaves[i] = a
+    return jax.tree.unflatten(treedef, leaves), binding

@@ -49,6 +49,7 @@ import numpy as np
 from repro.core.adapt import Replanner, WindowStats
 from repro.core.plan import EndpointPlan, Hints, SharingVector, as_plan
 from repro.models.model import Model
+from repro.models.params import serving_params
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NOOP_OBS, Observability
 from repro.serve.engine import ContinuousEngine, Request, ServeEngine
@@ -131,7 +132,10 @@ class ServeClient:
             raise ValueError(f"unknown placement {plan.placement!r}; "
                              f"one of {sorted(POLICIES)}")
         self.cfg = cfg
-        self.params = params
+        #: weights bound once in the compute dtype, shared by the engine
+        #: or every fleet replica (which then find nothing left to cast)
+        self.params, self.weight_binding = serving_params(
+            params, cfg, Model(cfg).plan)
         self.plan = plan
         #: tuned-plan store (DESIGN.md §16, duck-typed
         #: ``tune.PlanRepository``): consulted by hint re-resolution in
@@ -185,10 +189,12 @@ class ServeClient:
         self.engine = None            # single-executor engine
         self.workers: List[EngineWorker] = []
         if self.executor == "wave":
-            self.engine = ServeEngine(cfg, params, plan=plan)
+            self.engine = ServeEngine(cfg, self.params, plan=plan)
         elif self.executor == "continuous":
-            self.engine = ContinuousEngine(cfg, params, plan=plan,
+            self.engine = ContinuousEngine(cfg, self.params, plan=plan,
                                            exec_group=plan.exec_group_of(0))
+        if self.engine is not None:
+            self.engine.weight_binding = self.weight_binding
         # fleet workers are built lazily on the first run()
 
     # ----- submission -----------------------------------------------------
@@ -411,6 +417,8 @@ class ServeClient:
                                  exec_group=plan.exec_group_of(w)),
                 request_fn=request_fn)
             for w in range(plan.n_workers)]
+        for wk in self.workers:
+            wk.engine.weight_binding = self.weight_binding
 
     def _run_fleet(self, batch) -> Dict[int, List[int]]:
         """One router pass over fresh channels (the engines persist and

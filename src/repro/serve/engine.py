@@ -53,6 +53,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.plan import EndpointPlan, SharingVector
 from repro.models.model import Model
+from repro.models.params import SPAN_BIND_WEIGHTS, serving_params
 from repro.models.transformer import ragged_kv_block
 from repro.obs.metrics import Histogram
 from repro.obs.trace import host_span
@@ -65,7 +66,8 @@ from repro.serve.slots import SlotPool, _coerce_level
 # one K=1 step, ``engine.horizon`` one fused K-step horizon.  Children:
 # ``pack`` numpy packing, ``put`` host-to-device copies, ``launch`` the
 # program dispatch, ``sync`` the blocking readback, ``bind`` / ``emit``
-# the host bookkeeping after it.
+# the host bookkeeping after it.  ``engine.bind_weights``
+# (``SPAN_BIND_WEIGHTS``) is the one cast of the weights at bind time.
 SPAN_ADMIT = "engine.admit"
 SPAN_ADMIT_PACK = "engine.admit.pack"
 SPAN_ADMIT_PUT = "engine.admit.put"
@@ -130,7 +132,6 @@ class ServeEngine:
         if plan is not None:
             n_slots, max_len = plan.n_slots, plan.max_len
         self.cfg = cfg
-        self.params = params
         self.plan = plan or EndpointPlan(
             vector=SharingVector(slots=4), n_slots=n_slots,
             max_len=max_len, executor="wave")
@@ -148,6 +149,8 @@ class ServeEngine:
         self.model = steps.model
         self._decode = steps.decode
         self._prefill = steps.prefill
+        self.params, self.weight_binding = serving_params(
+            params, cfg, self.model.plan)
 
     def submit(self, req: Request):
         req.output = []
@@ -546,7 +549,6 @@ class ContinuousEngine:
             raise ValueError(f"decode_horizon must be >= 1, "
                              f"got {decode_horizon}")
         self.cfg = cfg
-        self.params = params
         self.n_slots = n_slots
         self.max_len = max_len
         self.pool = pool or SlotPool(
@@ -586,6 +588,10 @@ class ContinuousEngine:
         self.exec_group = exec_group
         self._steps = _shared_steps(cfg, use_ragged_kernel, exec_group)
         self.model = self._steps.model
+        #: weights in the compute dtype, bound once (DESIGN.md §6.2);
+        #: ``weight_binding`` counts the leaves and bytes that cast
+        self.params, self.weight_binding = serving_params(
+            params, cfg, self.model.plan)
         self._decode = self._steps.decode
         self._prefill = self._steps.prefill
         self._merge = self._steps.merge

@@ -213,6 +213,17 @@ def test_host_span_builds_args_only_while_collecting(tmp_path):
         ("engine.probe", {"n": 7})]
 
 
+def test_binding_records_its_cast_span(tmp_path):
+    cfg, params = _served()
+    with jax.profiler.trace(str(tmp_path)):
+        eng = ContinuousEngine(cfg, params, n_slots=N_SLOTS,
+                               max_len=MAX_LEN)
+    (spans,) = _host_spans(str(tmp_path))
+    assert [(name, args) for _, _, name, args in spans] == [
+        (E.SPAN_BIND_WEIGHTS, eng.weight_binding)]
+    assert eng.weight_binding["leaves"] == len(jax.tree.leaves(params))
+
+
 @pytest.mark.parametrize("program,name", [
     ("decode", "jit_decode_step"), ("horizon", "jit_decode_horizon"),
     ("admit_packed", "jit_admit_packed"), ("prefill", "jit_prefill"),

@@ -85,6 +85,13 @@ def _cache_spec_for(path_keys, leaf, cfg: ArchConfig, mesh, batch: int) -> P:
     bs = batch_spec(mesh, batch)
     bax = bs[0] if len(bs) else None
 
+    if name in ("k", "v") and len(shape) == 3:
+        # self-attention rows (B, S, width): whole heads over model, when
+        # the row holds no lane padding
+        spec = kv_cache_spec(mesh, batch, cfg.n_kv_heads, cfg.head_dim)
+        heads = (len(spec) == 4 and spec[2] == "model"
+                 and shape[2] == cfg.n_kv_heads * cfg.head_dim)
+        return P(*lead, bax, None, "model" if heads else None)
     if name in ("k", "v"):
         spec = kv_cache_spec(mesh, batch, shape[2], shape[3])
         return P(*lead, *spec)

@@ -199,7 +199,7 @@ class Model:
         lengths in one shared cache).
 
         ``page_size > 0`` builds the PAGED cache: attention k/v become
-        ``(n_pages, page_size, Hkv, dh)`` shared physical pages and the
+        ``(n_pages, page_size, width)`` shared physical pages and the
         cache carries a sentinel-filled per-slot page table ``pt`` of
         shape ``(B, max_len // page_size)`` (sentinel = ``n_pages``).
         Requires ``supports_paged_cache``."""

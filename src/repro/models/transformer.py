@@ -30,6 +30,9 @@ from repro.models.xlstm import (apply_mlstm_block, apply_slstm_block,
 from repro.models.params import stack_specs
 
 ATTN_KINDS = ("attn", "attn_local")
+#: lanes of a TPU vector register: a TPU keeps an array row-major only when
+#: its minor dimension fills whole lanes (else it puts a larger axis minor)
+LANES = 128
 
 
 def _remat_group(n_periods: int) -> int:
@@ -149,40 +152,66 @@ class BlockCtx:
     #                                   (DESIGN.md §13); None = contiguous
 
 
+def kv_row_width(cfg: ArchConfig) -> int:
+    """Width of one attention cache row: a position's ``Hkv * dh`` k (or
+    v) values, padded with zeros to whole lanes."""
+    return -(-cfg.n_kv_heads * cfg.head_dim // LANES) * LANES
+
+
+def _kv_rows(x, width: int):
+    """(..., Hkv, dh) -> (..., width): one zero-padded cache row per
+    position."""
+    rows = x.reshape(x.shape[:-2] + (-1,))
+    pad = width - rows.shape[-1]
+    if not pad:
+        return rows
+    return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+
+
+def _kv_heads(rows, cfg: ArchConfig):
+    """(..., width) cache rows -> (..., Hkv, dh) for attention."""
+    n = cfg.n_kv_heads * cfg.head_dim
+    return rows[..., :n].reshape(rows.shape[:-1]
+                                 + (cfg.n_kv_heads, cfg.head_dim))
+
+
 @jax.named_scope("kv_write")
 def _attn_cache_write(cache, k_new, v_new, idx, window: int, rolling: bool,
-                      write_mask=None):
-    idx = jnp.asarray(idx)
-    if idx.ndim == 1:
-        # per-slot write positions (continuous batching): batch row b lands
-        # at idx[b]; rows whose index ran past the buffer end write nowhere
-        # (retired slots decoding into the masked void).  ``write_mask``
-        # additionally gates whole rows — the fused decode horizon passes
-        # the live-slot mask so finished slots stop writing mid-horizon.
-        slot = idx % window if (rolling and window > 0) else idx
-        smax = cache["k"].shape[1]
-        hit = jnp.arange(smax)[None, :] == slot[:, None]     # (B, Smax)
-        if write_mask is not None:
-            hit &= write_mask[:, None]
-        k = jnp.where(hit[..., None, None], k_new, cache["k"])
-        v = jnp.where(hit[..., None, None], v_new, cache["v"])
-        return {"k": k, "v": v}
+                      write_mask=None, layer=None):
+    """Write one decode token per batch row into the cache, in place.
+
+    ``idx`` is the scalar or per-slot (B,) position: row b lands at
+    ``idx[b]`` (``% window`` on a rolling cache).  Rows that must not
+    write -- positions past the buffer end (retired slots decoding into
+    the masked void) and rows ``write_mask`` switches off (the fused
+    horizon's finished slots) -- are sent out of bounds, where
+    ``mode="drop"`` discards them.  ``layer`` indexes the leading axis of
+    a layer-stacked cache (the scan's carried body cache); None writes a
+    single layer's buffer.  Each row is one scatter element, so the step
+    writes B rows and never rewrites the buffer."""
+    smax = cache["k"].shape[-2]
+    b = k_new.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(idx, jnp.int32), (b,))
     if rolling and window > 0:
-        slot = idx % window
-    else:
-        slot = idx
-    k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
-    return {"k": k, "v": v}
+        pos = pos % window
+    if write_mask is not None:
+        pos = jnp.where(write_mask, pos, smax)
+    at = (jnp.arange(b), pos) if layer is None else (layer, jnp.arange(b),
+                                                      pos)
+    width = cache["k"].shape[-1]
+    return {name: cache[name].at[at].set(_kv_rows(new[:, 0], width),
+                                         mode="drop", unique_indices=True)
+            for name, new in (("k", k_new), ("v", v_new))}
 
 
 @jax.named_scope("kv_write")
 def _attn_cache_write_paged(cache, k_new, v_new, idx, page_table,
-                            write_mask=None):
-    """Scatter one decode step's k/v into a PAGED cache.
+                            write_mask=None, layer=None):
+    """Scatter one decode step's k/v into a PAGED cache, in place.
 
-    ``cache``: {"k": (N, page_size, Hkv, dh), "v": ...} physical pages
-    shared by every slot; ``idx``: (B,) per-slot positions;
+    ``cache``: {"k": (N, page_size, width), "v": ...} physical pages
+    shared by every slot (with a leading layer axis when ``layer`` is
+    given); ``idx``: (B,) per-slot positions;
     ``page_table``: (B, max_pages) int32 mapping each slot's logical page
     j to a physical page (sentinel N = unmapped).  Row b lands at flat
     position ``pt[b, idx[b]//ps] * ps + idx[b] % ps``; rows that must not
@@ -190,7 +219,7 @@ def _attn_cache_write_paged(cache, k_new, v_new, idx, page_table,
     pages — are sent out of bounds, where ``mode="drop"`` discards them.
     No aliasing: live slots own pairwise-disjoint pages (PagePool
     invariant), so distinct rows always scatter to distinct flat rows."""
-    n, ps = cache["k"].shape[0], cache["k"].shape[1]
+    n, ps = cache["k"].shape[-3], cache["k"].shape[-2]
     max_pages = page_table.shape[1]
     max_len = max_pages * ps
     idx = jnp.asarray(idx)
@@ -202,12 +231,34 @@ def _attn_cache_write_paged(cache, k_new, v_new, idx, page_table,
     flat = jnp.where(idx < max_len, flat, oob)
     if write_mask is not None:
         flat = jnp.where(write_mask, flat, oob)
-    tail = cache["k"].shape[2:]
-    k = cache["k"].reshape((n * ps,) + tail).at[flat].set(
-        k_new[:, 0], mode="drop").reshape(cache["k"].shape)
-    v = cache["v"].reshape((n * ps,) + tail).at[flat].set(
-        v_new[:, 0], mode="drop").reshape(cache["v"].shape)
-    return {"k": k, "v": v}
+    at = (flat,) if layer is None else (layer, flat)
+
+    def put(pages, new):
+        rows = pages.reshape(pages.shape[:-3] + (n * ps, pages.shape[-1]))
+        return rows.at[at].set(_kv_rows(new[:, 0], pages.shape[-1]),
+                               mode="drop",
+                               unique_indices=True).reshape(pages.shape)
+
+    return {"k": put(cache["k"], k_new), "v": put(cache["v"], v_new)}
+
+
+def _layer_view(tree, layer):
+    """One layer's slice of a layer-stacked cache (``layer`` None: the
+    tree is already one layer's)."""
+    if layer is None or tree is None:
+        return tree
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        tree)
+
+
+def _layer_put(stack, tree, layer):
+    """Write one layer's slice back into a layer-stacked cache."""
+    if layer is None or tree is None:
+        return tree
+    return jax.tree.map(
+        lambda s, a: jax.lax.dynamic_update_index_in_dim(s, a, layer, 0),
+        stack, tree)
 
 
 def ragged_kv_block(smax: int, target: int = 256) -> int:
@@ -238,7 +289,11 @@ def _decode_valid_mask(smax, idx, window: int, rolling: bool):
 
 
 @jax.named_scope("attn")
-def _self_attention(p, h, ctx: BlockCtx, window: int, cache):
+def _self_attention(p, h, ctx: BlockCtx, window: int, cache, layer=None):
+    """-> (out, new_cache).  In decode, ``layer`` (the scan's period
+    index) says ``cache`` is the whole layer-stacked body cache: the new
+    row is written into it in place and attention reads this layer's
+    slice after the write; the returned cache is the updated stack."""
     cfg = ctx.cfg
     q = project_q(p, h, cfg)
     k, v = project_kv(p, h, cfg)
@@ -257,9 +312,11 @@ def _self_attention(p, h, ctx: BlockCtx, window: int, cache):
         # Engine-side eligibility (Model.supports_paged_cache) guarantees
         # full-context attention only — no rolling windows here.
         from repro.models.attention import attention_decode_paged
-        new_kv = _attn_cache_write_paged(
+        new_cache = _attn_cache_write_paged(
             cache, k, v, ctx.decode_idx, ctx.page_table,
-            write_mask=ctx.decode_write_mask)
+            write_mask=ctx.decode_write_mask, layer=layer)
+        new_kv = {name: _kv_heads(a, cfg)
+                  for name, a in _layer_view(new_cache, layer).items()}
         ps = new_kv["k"].shape[1]
         if ctx.ragged_kernel and jnp.ndim(ctx.decode_idx) == 1:
             from repro.kernels.flash_attention.ops import \
@@ -274,15 +331,19 @@ def _self_attention(p, h, ctx: BlockCtx, window: int, cache):
                 max_len=ctx.page_table.shape[1] * ps,
                 softcap=cfg.attn_logit_softcap)
         return jnp.einsum("bshk,hkd->bsd", out,
-                          p["wo"].astype(h.dtype)), new_kv
+                          p["wo"].astype(h.dtype)), new_cache
     if ctx.mode == "decode":
         rolling = ctx.window_cache and window > 0
-        new_kv = _attn_cache_write(cache, k, v, ctx.decode_idx, window,
-                                   rolling, write_mask=ctx.decode_write_mask)
+        new_cache = _attn_cache_write(cache, k, v, ctx.decode_idx, window,
+                                      rolling,
+                                      write_mask=ctx.decode_write_mask,
+                                      layer=layer)
+        new_kv = {name: _kv_heads(a, cfg)
+                  for name, a in _layer_view(new_cache, layer).items()}
         if rolling:
             # every live slot holds one of the last `window` positions; only
             # not-yet-written slots (buffer not full) are invalid
-            smax = cache["k"].shape[1]
+            smax = new_kv["k"].shape[1]
             idx = jnp.asarray(ctx.decode_idx)
             j = jnp.arange(smax)
             if idx.ndim == 1:           # per-slot ragged positions
@@ -307,7 +368,6 @@ def _self_attention(p, h, ctx: BlockCtx, window: int, cache):
             out = attention_decode(q, new_kv["k"], new_kv["v"],
                                    ctx.decode_idx, window=window,
                                    softcap=cfg.attn_logit_softcap)
-        new_cache = new_kv
     else:
         out = ctx.attn_fn(q, k, v, causal=ctx.causal, window=window,
                           softcap=cfg.attn_logit_softcap)
@@ -323,14 +383,16 @@ def _self_attention(p, h, ctx: BlockCtx, window: int, cache):
                 else:
                     pad = [(0, 0), (0, window - s), (0, 0), (0, 0)]
                     k_tail, v_tail = jnp.pad(k, pad), jnp.pad(v, pad)
-                new_cache = {"k": k_tail, "v": v_tail}
+                width = kv_row_width(cfg)
+                new_cache = {"k": _kv_rows(k_tail, width),
+                             "v": _kv_rows(v_tail, width)}
             else:
                 # write the prompt into the (possibly longer) decode buffer
                 new_cache = {
-                    "k": jax.lax.dynamic_update_slice_in_dim(
-                        cache["k"], k, 0, axis=1),
-                    "v": jax.lax.dynamic_update_slice_in_dim(
-                        cache["v"], v, 0, axis=1)}
+                    name: jax.lax.dynamic_update_slice_in_dim(
+                        cache[name], _kv_rows(new, cache[name].shape[-1]), 0,
+                        axis=1)
+                    for name, new in (("k", k), ("v", v))}
     return jnp.einsum("bshk,hkd->bsd", out,
                       p["wo"].astype(h.dtype)), new_cache
 
@@ -351,8 +413,11 @@ def _cross_attention(p, h, ctx: BlockCtx, cache):
                       p["wo"].astype(h.dtype)), new_cache
 
 
-def apply_block(p, x, desc: LayerDesc, ctx: BlockCtx, cache=None):
-    """-> (x, new_cache, aux_loss)."""
+def apply_block(p, x, desc: LayerDesc, ctx: BlockCtx, cache=None,
+                layer=None):
+    """-> (x, new_cache, aux_loss).  ``layer`` (decode in the scanned
+    body): ``cache`` holds every period's leaves stacked on a leading
+    axis, and the block reads and writes its own slice ``layer``."""
     cfg = ctx.cfg
     aux = jnp.zeros((), jnp.float32)
     h = apply_norm(p["norm1"], x, cfg.norm)
@@ -362,32 +427,24 @@ def apply_block(p, x, desc: LayerDesc, ctx: BlockCtx, cache=None):
     new_cache = dict(sub_cache)
     if desc.kind in ATTN_KINDS:
         out, c = _self_attention(p["attn"], h, ctx, window,
-                                 sub_cache.get("attn"))
+                                 sub_cache.get("attn"), layer)
         if c is not None and ctx.mode != "train":
             new_cache["attn"] = c
-    elif desc.kind == "rglru":
-        out, c = apply_rglru_block(p["rglru"], h, cfg,
-                                   sub_cache.get("rglru"))
+    else:
+        apply = {"rglru": apply_rglru_block, "mlstm": apply_mlstm_block,
+                 "slstm": apply_slstm_block}[desc.kind]
+        state = sub_cache.get(desc.kind)
+        out, c = apply(p[desc.kind], h, cfg, _layer_view(state, layer))
         if c is not None:
-            new_cache["rglru"] = c
-    elif desc.kind == "mlstm":
-        out, c = apply_mlstm_block(p["mlstm"], h, cfg,
-                                   sub_cache.get("mlstm"))
-        if c is not None:
-            new_cache["mlstm"] = c
-    else:  # slstm
-        out, c = apply_slstm_block(p["slstm"], h, cfg,
-                                   sub_cache.get("slstm"))
-        if c is not None:
-            new_cache["slstm"] = c
+            new_cache[desc.kind] = _layer_put(state, c, layer)
     x = x + out
 
     if desc.cross:
         hc = apply_norm(p["norm_cross"], x, cfg.norm)
         out, c = _cross_attention(p["cross"], hc, ctx,
-                                  sub_cache.get("cross"))
-        if c is not None and ctx.mode != "train":
-            new_cache["cross"] = c
+                                  _layer_view(sub_cache.get("cross"), layer))
+        if c is not None and ctx.mode != "train" and layer is None:
+            new_cache["cross"] = c      # decode's cross k/v are read-only
         x = x + out
 
     if desc.ffn in ("dense", "dense0"):
@@ -417,8 +474,17 @@ def init_stack_cache(cfg: ArchConfig, plan: LayerPlan, batch: int,
                      n_pages: int = 0):
     """Materialized (zeros) cache for the whole stack.
 
+    Attention k/v hold one row per position, ``(batch, S, width)``: the
+    position's ``Hkv * dh`` values padded to whole lanes
+    (``kv_row_width``), so a decode step writes each slot's token as one
+    contiguous row.  With the heads as separate minor axes (``dh`` of 64
+    on qwen2-0.5b), or a row that does not fill whole lanes (320 on
+    smollm-360m), a TPU lays the cache out with the sequence minor, where
+    a token is a strided column and XLA copies the whole cache into
+    another layout around every row scatter.
+
     ``page_size > 0`` selects the PAGED layout (DESIGN.md §13): each
-    attention layer's k/v become ``(n_pages, page_size, Hkv, dh)``
+    attention layer's k/v become ``(n_pages, page_size, width)``
     physical pages with no batch axis — slots address them through the
     shared page table the model threads via ``BlockCtx.page_table``."""
     def one(desc: LayerDesc):
@@ -427,16 +493,14 @@ def init_stack_cache(cfg: ArchConfig, plan: LayerPlan, batch: int,
             window = cfg.attn_window if desc.kind == "attn_local" else 0
             s = min(max_len, window) if (window_cache and window) else max_len
             dt = jnp.dtype(cfg.compute_dtype)
+            row = kv_row_width(cfg)
             if page_size > 0:
                 assert not (window_cache and window), \
                     "paged cache excludes rolling-window layers"
-                shape = (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-                c["attn"] = {"k": jnp.zeros(shape, dt),
-                             "v": jnp.zeros(shape, dt)}
-                return c
-            c["attn"] = {
-                "k": jnp.zeros((batch, s, cfg.n_kv_heads, cfg.head_dim), dt),
-                "v": jnp.zeros((batch, s, cfg.n_kv_heads, cfg.head_dim), dt)}
+                shape = (n_pages, page_size, row)
+            else:
+                shape = (batch, s, row)
+            c["attn"] = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
         elif desc.kind == "rglru":
             c["rglru"] = init_rglru_cache(cfg, batch)
         elif desc.kind == "mlstm":
@@ -486,12 +550,10 @@ def apply_stack(params, x, cfg: ArchConfig, plan: LayerPlan, ctx: BlockCtx,
     p_body = tuple(params["body"])
     c_body = tuple(cache["body"]) if has_cache else None
 
-    def body_fn(carry, xs):
-        xx, aux_acc = carry
-        p_list, c_list = xs if has_cache else (xs, (None,) * len(p_body))
+    def run_period(xx, aux_acc, p_list, c_list, layer=None):
         c_news = []
         for pos, desc in enumerate(plan.period):
-            blk = partial(apply_block, desc=desc, ctx=ctx)
+            blk = partial(apply_block, desc=desc, ctx=ctx, layer=layer)
             if remat and ctx.mode == "train" and len(plan.period) > 1:
                 # nested remat: the period recompute re-checkpoints each
                 # block so only one block's inner-scan residuals are ever
@@ -501,7 +563,28 @@ def apply_stack(params, x, cfg: ArchConfig, plan: LayerPlan, ctx: BlockCtx,
             xx = reshard(xx)
             aux_acc = aux_acc + aux
             c_news.append(c_new)
-        return (xx, aux_acc), (tuple(c_news) if has_cache else 0)
+        return xx, aux_acc, tuple(c_news)
+
+    if has_cache and ctx.mode == "decode" and plan.n_periods:
+        # decode writes one row per slot: the stacked body cache rides the
+        # carry, which the loop updates in place, instead of going in as
+        # ``xs`` and coming back as freshly stacked ``ys`` (a full rewrite
+        # of every layer's cache per token step)
+        def decode_fn(carry, xs):
+            xx, aux_acc, c_stack = carry
+            p_list, layer = xs
+            return run_period(xx, aux_acc, p_list, c_stack, layer), None
+
+        (x, aux_total, c_out), _ = jax.lax.scan(
+            decode_fn, (x, aux_total, c_body),
+            (p_body, jnp.arange(plan.n_periods)))
+        return x, {"prefix": new_prefix_cache, "body": list(c_out)}, \
+            aux_total
+
+    def body_fn(carry, xs):
+        p_list, c_list = xs if has_cache else (xs, (None,) * len(p_body))
+        xx, aux_acc, c_news = run_period(*carry, p_list, c_list)
+        return (xx, aux_acc), (c_news if has_cache else 0)
 
     train_remat = remat and ctx.mode == "train"
     group = _remat_group(plan.n_periods) if train_remat else 1

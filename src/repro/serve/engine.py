@@ -63,7 +63,9 @@ from repro.serve.slots import SlotPool, _coerce_level
 # Wall-clock spans of ``ContinuousEngine`` (``obs.host_span``), one at each
 # boundary where the host works or waits.  ``engine.admit`` is one
 # admission round (args ``rids``, ``rows``, ``bucket``); ``engine.decode``
-# one K=1 step, ``engine.horizon`` one fused K-step horizon.  Children:
+# one K=1 step, ``engine.horizon`` one fused K-step horizon (args ``rows``
+# and ``cache_in_place``, the engine's count of earlier decode launches
+# that consumed their cache).  Children:
 # ``pack`` numpy packing, ``put`` host-to-device copies, ``launch`` the
 # program dispatch, ``sync`` the blocking readback, ``bind`` / ``emit``
 # the host bookkeeping after it.  ``engine.bind_weights``
@@ -319,11 +321,15 @@ def _shared_steps_cached(cfg: ArchConfig, use_ragged_kernel: bool,
         }
         return cache, state
 
+    # the decode programs consume their cache (argument 1) and write the
+    # step's rows into it in place: every caller rebinds the returned
+    # cache and never touches the one it passed (DESIGN.md §6.2)
     return SharedSteps(
-        model=model, decode=jax.jit(decode_step),
+        model=model, decode=jax.jit(decode_step, donate_argnums=(1,)),
         prefill=jax.jit(prefill), merge=jax.jit(merge),
         admit_packed=jax.jit(admit_packed, static_argnums=(11,)),
-        horizon=jax.jit(decode_horizon, static_argnums=(3, 4)),
+        horizon=jax.jit(decode_horizon, static_argnums=(3, 4),
+                        donate_argnums=(1,)),
         merge_paged=jax.jit(merge_paged),
         admit_packed_paged=jax.jit(admit_packed_paged,
                                    static_argnums=(12,)))
@@ -461,6 +467,16 @@ def _scatter_slots_paged(full, many, has, src, lengths, pt, max_len):
     return {"stack": stack, "idx": idx, "pt": pt.astype(jnp.int32)}
 
 
+def _attn_leaf(cache):
+    """The cache's first attention k/v leaf (its first leaf when no layer
+    is attention)."""
+    leaves = jax.tree_util.tree_leaves_with_path(cache["stack"])
+    for path, leaf in leaves:
+        if any(getattr(key, "key", None) == "attn" for key in path):
+            return leaf
+    return leaves[0][1]
+
+
 def _cache_bytes(cache, tokens: int, max_len: int) -> int:
     """Bytes of KV actually resident in a batch-1 cache holding
     ``tokens`` of its ``max_len`` capacity — the size-proportional
@@ -575,8 +591,11 @@ class ContinuousEngine:
         # busy_slot_steps / slot_steps is the pool's occupancy;
         # admit_positions: token positions the admission prefills computed
         # (rows x bucket per packed round, the prompt on the exact-length
-        # path), of which admit_real_tokens were prompt tokens
+        # path), of which admit_real_tokens were prompt tokens;
+        # decode_cache_in_place: decode launches (decode_calls) whose
+        # donated input cache was consumed, so the step wrote it in place
         self.stats = {"decode_steps": 0, "decode_calls": 0,
+                      "decode_cache_in_place": 0,
                       "slot_steps": 0, "busy_slot_steps": 0,
                       "prefills": 0, "prefilled_requests": 0,
                       "host_syncs": 0, "regroups": 0,
@@ -1255,15 +1274,18 @@ class ContinuousEngine:
         active = [i for i, r in enumerate(self._slot_req) if r is not None]
         if not active:
             return []
-        with host_span(SPAN_DECODE, rows=len(active)):
+        with host_span(SPAN_DECODE, rows=len(active),
+                       cache_in_place=lambda: self.stats[
+                           "decode_cache_in_place"]):
             return self._step_one(active)
 
     def _step_one(self, active: List[int]) -> List[Request]:
         with host_span(SPAN_DECODE_PUT):
             toks = jnp.asarray(self._next_tok)
         with host_span(SPAN_DECODE_LAUNCH):
-            logits, self._cache = self._decode(self.params, self._cache,
-                                               toks)
+            cache = self._cache
+            logits, self._cache = self._decode(self.params, cache, toks)
+        self._count_in_place(cache)
         self.stats["decode_steps"] += 1
         self.stats["decode_calls"] += 1
         self.stats["host_syncs"] += 1
@@ -1293,20 +1315,31 @@ class ContinuousEngine:
         self._next_tok = nxt
         return retired
 
+    def _count_in_place(self, consumed) -> None:
+        """Count a decode launch whose donated cache was consumed: XLA
+        aliased it to the returned cache, so the launch wrote its rows in
+        place.  JAX only warns when it cannot, and then copies."""
+        self.stats["decode_cache_in_place"] += int(
+            _attn_leaf(consumed).is_deleted())
+
     def _step_fused(self) -> List[Request]:
         """One fused horizon: K decode steps on device, one host drain.
         The carry state never leaves the device — the trace transfer is
         the horizon's single host sync (the batched doorbell)."""
         if self.n_active == 0:
             return []
-        with host_span(SPAN_HORIZON, rows=self.n_active):
+        with host_span(SPAN_HORIZON, rows=self.n_active,
+                       cache_in_place=lambda: self.stats[
+                           "decode_cache_in_place"]):
             return self._horizon_once()
 
     def _horizon_once(self) -> List[Request]:
         k = self.decode_horizon
         with host_span(SPAN_HORIZON_LAUNCH):
+            cache = self._cache
             self._cache, self._dev_state, trace = self._steps.horizon(
-                self.params, self._cache, self._dev_state, k, self.max_len)
+                self.params, cache, self._dev_state, k, self.max_len)
+        self._count_in_place(cache)
         with host_span(SPAN_HORIZON_SYNC):
             # ONE blocking transfer drains the whole K-step token trace
             trace = jax.device_get(trace)

@@ -152,8 +152,12 @@ def test_write_mask_freezes_finished_rows():
                                write_mask=jnp.asarray([True, False]))
 
     def rows(tree, b):
-        return [np.asarray(leaf[b] if leaf.ndim == 4 else leaf[:, b])
-                for leaf in jax.tree.leaves(tree)]
+        # prefix leaves carry the batch axis first, scanned body leaves
+        # behind their leading layer axis
+        return ([np.asarray(leaf[b]) for leaf in jax.tree.leaves(
+                    tree["prefix"])]
+                + [np.asarray(leaf[:, b]) for leaf in jax.tree.leaves(
+                    tree["body"])])
 
     for before, after in zip(rows(cache["stack"], 1),
                              rows(out["stack"], 1)):

@@ -9,6 +9,8 @@ a fixture, never at import time, so every test worker collects the same
 tests and only the worker given this file loads the TPU compiler.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +21,7 @@ from repro.kernels.flash_attention.ops import (flash_attention,
                                                flash_decode_attention,
                                                paged_flash_decode_attention)
 from repro.kernels.rglru.ops import rglru_scan
+from repro.models.params import serving_params
 from repro.models.transformer import ragged_kv_block
 
 DECODE_ARCHS = ("qwen2-0.5b", "smollm-360m")
@@ -99,3 +102,87 @@ def test_rglru_scan_compiles_at_recurrentgemma_width(one_chip):
     cfg = get_config("recurrentgemma-2b")
     x = _sds((1, 2048, cfg.lru_width), jnp.float32, one_chip)
     _assert_kernel(rglru_scan, x, x, interpret=False)
+
+
+# the serving cell's cache: 32 slots of 2048 positions
+CELL_SLOTS, CELL_LEN = 32, 2048
+INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.-]+ = (.+?) ([a-z][\w-]*)\(")
+HEADER = re.compile(r"^(?:ENTRY )?%([\w.-]+) \(.*\{$")
+
+
+def _cache_rewrites(text, shapes):
+    """Instructions of the ``kv_write`` scope that produce an array of one
+    of ``shapes`` other than by a scatter, directly or as the root of a
+    fusion (a scatter updates its operand in place)."""
+    roots, comp = {}, None
+    for line in text.splitlines():
+        head = HEADER.match(line)
+        if head:
+            comp = head.group(1)
+        m = INSTR.match(line)
+        if m and line.lstrip().startswith("ROOT"):
+            roots[comp] = m.group(2)
+    bad = []
+    for line in text.splitlines():
+        m = INSTR.match(line)
+        if not m or "kv_write" not in line:
+            continue
+        shape, op = m.groups()
+        if not any(shape.startswith(f"bf16[{s}]") for s in shapes):
+            continue
+        called = re.search(r"calls=%([\w.-]+)", line)
+        if op == "fusion" and called:
+            op = roots.get(called.group(1), op)
+        if op not in ("scatter", "parameter", "get-tuple-element",
+                      "bitcast"):
+            bad.append(line.strip()[:160])
+    return bad
+
+
+@pytest.fixture(scope="module")
+def cell_decode(one_chip):
+    """The serving cell's decode programs at qwen2-0.5b's published
+    widths, bfloat16 weights bound as serving binds them, compiled for
+    one v5e: ``{name: (compiled, cache bytes)}``."""
+    from repro.serve.engine import _shared_steps
+    arch = get_config("qwen2-0.5b")
+    steps = _shared_steps(arch, False)
+    model = steps.model
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                            tree)
+
+    params = shaped(jax.eval_shape(
+        lambda p: serving_params(p, arch, model.plan)[0],
+        model.abstract_params()))
+    cache = shaped(jax.eval_shape(lambda: model.init_cache(
+        CELL_SLOTS, CELL_LEN, per_slot=True)))
+    i32 = _sds((CELL_SLOTS,), jnp.int32, one_chip)
+    flag = _sds((CELL_SLOTS,), jnp.bool_, one_chip)
+    state = {"tok": i32, "remaining": i32, "finished": flag, "eos": i32,
+             "has_eos": flag}
+    kv_bytes = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(cache["stack"]))
+    return {"decode": (steps.decode.lower(params, cache, i32).compile(),
+                       kv_bytes),
+            "horizon": (steps.horizon.lower(params, cache, state, 8,
+                                            CELL_LEN).compile(), kv_bytes)}
+
+
+@pytest.mark.parametrize("program", ["decode", "horizon"])
+def test_decode_programs_write_the_cache_in_place(cell_decode, program):
+    """The decode step and the K=8 horizon alias their donated cache to
+    the returned one, allocate no second cache, and write the step's K/V
+    rows by scatter: no ``kv_write`` op rewrites a layer's or the whole
+    stack's (32, 2048) cache."""
+    compiled, kv_bytes = cell_decode[program]
+    mem = compiled.memory_analysis()
+    assert kv_bytes == 24 * 2 * CELL_SLOTS * CELL_LEN * 2 * 64 * 2
+    assert mem.alias_size_in_bytes >= kv_bytes
+    limit = 64 * 2 ** 20       # one layer's K and V: 33.5 MB
+    assert mem.temp_size_in_bytes < limit
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < limit
+    shapes = [f"{lead}{CELL_SLOTS},{CELL_LEN},{tail}"
+              for lead in ("", "24,") for tail in ("128", "2,64")]
+    assert _cache_rewrites(compiled.as_text(), shapes) == []
